@@ -58,6 +58,9 @@ def main(argv: list[str] | None = None) -> int:
 
     from repro.api import CompiledModel, build  # lazy: --help stays instant
     from repro.core.deploy import DeployConfig
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     try:
         imported = load_model(args.dump, format=args.format)

@@ -99,6 +99,9 @@ def main(argv: list[str] | None = None) -> int:
                          "its queries and verify both kinds bit-exactly")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     artifact = load_artifact(args.artifact)
     if args.expected:
         return _verify(artifact, args.expected, args.chunk_rows)

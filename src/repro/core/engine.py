@@ -46,16 +46,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 re-exports it at the top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - version-dependent import path
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.compile import CAMTable
 from repro.core.deploy import DeployConfig
 from repro.core.precision import get_cell_mode
 from repro.kernels import ops as kops
-from repro.kernels.cam_match import default_interpret, pallas_available
+from repro.kernels.cam_match import default_interpret
 from repro.kernels.ref import cam_match_ref
 
 _UNSET = object()  # distinguishes "kwarg not passed" from an explicit default
@@ -81,20 +76,6 @@ def resolve_table_dtype(table: CAMTable, config: DeployConfig) -> str:
             "(inclusive bounds store values up to n_bins-1)"
         )
     return dt
-
-
-def _wrap_shard_map(fn, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off (the Pallas kernel body
-    is opaque to the rep-rule checker); the flag was renamed ``check_rep``
-    -> ``check_vma`` across jax versions, so try both before giving it up
-    entirely."""
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    for check_kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map(fn, **kw, **check_kw)
-        except TypeError:  # pragma: no cover - version-dependent signature
-            continue
-    raise TypeError("no compatible shard_map signature found")
 
 
 @dataclass
@@ -140,6 +121,7 @@ class XTimeEngine:
         *,
         config: DeployConfig | None = None,
         mesh: Mesh | None = None,
+        place: bool = True,
         backend=_UNSET,
         mode=_UNSET,
         row_axis=_UNSET,
@@ -193,17 +175,6 @@ class XTimeEngine:
             else np.asarray(table.col_perm, dtype=np.int64)
         )
         self.backend = config.backend
-        if self.backend == "pallas" and not pallas_available():
-            # jaxlib builds without the pallas TPU extension can't run the
-            # v2 kernel even interpreted; the jnp oracle computes the same
-            # bits, so degrade loudly instead of crashing at first predict
-            warnings.warn(
-                "pallas TPU support unavailable in this jaxlib; engine "
-                "falls back to the jnp oracle (identical results)",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            self.backend = "jnp"
         self.mode = config.mode
         self.mesh = mesh
         self.row_axis = config.row_axis
@@ -328,19 +299,23 @@ class XTimeEngine:
             m_pad = np.zeros((self.arrays.r_pad, c3_pad), dtype=np.float32)
             m_pad[:R, : 3 * C] = m
             self._moments = jnp.asarray(m_pad)
-        if mesh is not None:
+        if mesh is not None and place:
             self._place_on_mesh()
         self._fn_cache: dict = {}
 
     @classmethod
     def from_config(
-        cls, table: CAMTable, config: DeployConfig, *, mesh: Mesh | None = None
+        cls, table: CAMTable, config: DeployConfig, *,
+        mesh: Mesh | None = None, place: bool = True,
     ) -> "XTimeEngine":
         """Canonical constructor: bind a compiled table + deploy config to a
         backend/mesh.  ``config.noc_config`` must already be resolved
         ('auto' is treated as 'accumulate'); ``CompiledModel.engine``
-        resolves it from the NoC plan first."""
-        return cls(table, config=config, mesh=mesh)
+        resolves it from the NoC plan first.  ``place=False`` makes every
+        mesh-dependent decision but leaves the arrays unplaced — for
+        lowering against a described topology, whose devices hold no
+        arrays."""
+        return cls(table, config=config, mesh=mesh, place=place)
 
     # -- placement ---------------------------------------------------------
 
@@ -453,7 +428,12 @@ class XTimeEngine:
                 return out
 
             qs, rs = self._batch_spec(), self._row_spec()
-            return _wrap_shard_map(body, self.mesh, (qs, rs, rs, rs, rs), qs)
+            # replication checking off: the Pallas kernel body is opaque
+            # to the varying-manual-axes checker
+            return jax.shard_map(
+                body, mesh=self.mesh, in_specs=(qs, rs, rs, rs, rs),
+                out_specs=qs, check_vma=False,
+            )
         return kernel
 
     def _jitted(self, key: str, donate: bool = False) -> Callable:
@@ -688,6 +668,16 @@ class XTimeEngine:
         bs = NamedSharding(self.mesh, self._batch_spec())
         rs = NamedSharding(self.mesh, self._row_spec())
         return margin, (bs, rs, rs, rs, rs), bs
+
+    def compiled_text(self, kind: str = "margin") -> str:
+        """HLO text of the compiled ``kind`` program at its smallest
+        admissible batch — how a caller sees what the device runs (the
+        compiled Pallas kernel appears as ``tpu_custom_call``, the NoC
+        collectives as ``all-reduce`` & co.)."""
+        a = self.arrays
+        q = self.input_specs(int(np.lcm(self.b_blk, self.batch_multiple)))
+        lowered = self._jitted(kind).lower(q, a.low, a.high, a.leaf, a.tile_mask)
+        return lowered.compile().as_text()
 
     def input_specs(self, batch: int) -> jax.ShapeDtypeStruct:
         return jax.ShapeDtypeStruct(

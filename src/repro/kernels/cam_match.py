@@ -10,9 +10,11 @@ Kernel v2 (DESIGN.md §10) differs from the v1 layout in three ways:
   * **compact dtypes** — the threshold tables stream in the narrowest
     dtype the bin grid permits (uint8 for the paper's native 256 bins,
     uint16 to 65536, int32 beyond / for the faithful cell modes).  Packed
-    tables store INCLUSIVE upper bounds so [0, n_bins) fits the dtype;
-    the compare runs natively (no upcast) — 4x less VMEM traffic than
-    the v1 int32 tables at identical results;
+    tables store INCLUSIVE upper bounds so [0, n_bins) fits the dtype —
+    4x less HBM traffic than the v1 int32 tables at identical results.
+    Inside the kernel the loaded tiles widen to int32 before the compare
+    (Mosaic cannot lay out the packed 3-D broadcast compare); HBM and
+    VMEM stay narrow;
   * **feature grid dimension** — the in-kernel Python loop over feature
     chunks is replaced by a third (feature) grid axis.  The running AND
     accumulates in a (b_blk, r_blk) VMEM scratch across feature tiles,
@@ -20,7 +22,10 @@ Kernel v2 (DESIGN.md §10) differs from the v1 layout in three ways:
   * **wildcard tile skipping** — a per-(row-tile, feature-tile) activity
     mask lets the kernel skip the compare for tiles that are all
     wildcards (an all-wildcard tile matches everything).  The compiler's
-    wildcard-aware row ordering maximizes such tiles.
+    wildcard-aware row ordering maximizes such tiles.  The mask rides
+    scalar prefetch as a FLAT 1-D SMEM vector (``j * n_f_tiles + k``):
+    a (1, 1) VMEM block breaks the TPU's (8, 128) tiling rule, and a 2-D
+    SMEM array pads its minor dim — 2 MiB at paper scale, over SMEM.
 
 Grid = (B/b_blk, R/r_blk, F_pad/f_blk); the batch axis is parallel, the
 row and feature axes are ``arbitrary`` (sequential) so the scratch AND
@@ -30,7 +35,7 @@ MXU — the systolic replacement for the analog wired-OR / sequential MMR.
 
 The ``mode`` switch selects the cell-level comparison:
   'direct'    — ideal 8/16-bit compare on exclusive-high int32 tables,
-  'inclusive' — the packed-table compare (low <= q <= high, native dtype),
+  'inclusive' — the packed-table compare (low <= q <= high, inclusive bound),
   'msb_lsb'   — the paper's Eq. 3 macro-cell arithmetic (faithful mode),
   'two_cycle' — Table-I cycle-accurate discharge semantics,
   'soft'      — sigmoid match SCORES on float32 soft-encoded tables
@@ -52,6 +57,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import precision
 
@@ -74,21 +80,8 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def pallas_available() -> bool:
-    """Can the v2 kernel run here?  The VMEM scratch accumulator needs
-    ``jax.experimental.pallas.tpu``; a jaxlib without it cannot run the
-    kernel even in interpret mode — the engine falls back to the jnp
-    oracle instead (same bits)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-        return hasattr(pltpu, "VMEM")
-    except ImportError:  # pragma: no cover - jaxlib-build dependent
-        return False
-
-
 def _cam_match_kernel(
-    mask_ref,  # (1, 1) int32 — tile activity for this (row, feature) tile
+    mask_ref,  # (n_r_tiles * n_f_tiles,) int32 SMEM — flat tile activity
     q_ref,  # (B_blk, f_blk) table dtype
     low_ref,  # (R_blk, f_blk) table dtype
     high_ref,  # (R_blk, f_blk) table dtype
@@ -117,11 +110,16 @@ def _cam_match_kernel(
         else:
             acc_ref[...] = jnp.ones_like(acc_ref[...])
 
-    @pl.when(mask_ref[0, 0] != 0)
+    @pl.when(mask_ref[j * n_f_tiles + k] != 0)
     def _compare():  # skipped for all-wildcard tiles (they match everything)
-        q = q_ref[...][:, None, :]  # (B_blk, 1, f_blk)
-        lo = low_ref[...][None, :, :]  # (1, R_blk, f_blk)
-        hi = high_ref[...][None, :, :]
+        q, lo, hi = q_ref[...], low_ref[...], high_ref[...]
+        if not soft and q.dtype != jnp.int32:
+            # packed uint8/uint16 tiles widen after the load: exact, and
+            # the int32 broadcast compare is one Mosaic can lay out
+            q, lo, hi = (x.astype(jnp.int32) for x in (q, lo, hi))
+        q = q[:, None, :]  # (B_blk, 1, f_blk)
+        lo = lo[None, :, :]  # (1, R_blk, f_blk)
+        hi = hi[None, :, :]
         if soft:
             logs = precision.soft_cell_logscore(q, lo, hi, tau)
             acc_ref[...] += jnp.sum(logs, axis=-1)  # (B_blk, R_blk)
@@ -135,9 +133,12 @@ def _cam_match_kernel(
             jnp.exp(acc_ref[...]) if soft
             else acc_ref[...].astype(jnp.float32)
         )
+        # HIGHEST: the TPU's default f32 matmul rounds the leaf values to
+        # bfloat16 (about 1e-3 relative); the margins must stay float32
         partial = jax.lax.dot(
             match,
             leaf_ref[...],
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )  # (B_blk, C_pad)
 
@@ -236,47 +237,35 @@ def cam_match_pallas(
         n_r_tiles=n_r_tiles, fuse_bias=bias is not None, tau=float(tau),
     )
 
-    if not pallas_available():  # pragma: no cover - jaxlib-build dependent
-        raise RuntimeError(
-            "pallas TPU scratch allocation unavailable on this jaxlib; "
-            "use the jnp backend (the engine falls back automatically)"
-        )
-    from jax.experimental.pallas import tpu as pltpu
-
     # the running accumulator: wired-AND bits for the hard modes, the
     # running log-score sum for 'soft'
     acc_dtype = jnp.float32 if mode == "soft" else jnp.int32
-    scratch = [pltpu.VMEM((b_blk, r_blk), acc_dtype)]
-    compiler_params = None
-    if not interpret:
-        try:
-            # batch axis parallel; row + feature axes sequential (the
-            # scratch AND and output tile accumulate in place)
-            compiler_params = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")
-            )
-        except AttributeError:  # pragma: no cover - older pltpu API
-            compiler_params = None
-
+    # index maps take the prefetched mask as a trailing argument
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i, j, k: (j, k)),  # tile activity
-        pl.BlockSpec((b_blk, f_blk), lambda i, j, k: (i, k)),  # queries
-        pl.BlockSpec((r_blk, f_blk), lambda i, j, k: (j, k)),  # CAM low
-        pl.BlockSpec((r_blk, f_blk), lambda i, j, k: (j, k)),  # CAM high
-        pl.BlockSpec((r_blk, C_pad), lambda i, j, k: (j, 0)),  # leaf matrix
+        pl.BlockSpec((b_blk, f_blk), lambda i, j, k, m: (i, k)),  # queries
+        pl.BlockSpec((r_blk, f_blk), lambda i, j, k, m: (j, k)),  # CAM low
+        pl.BlockSpec((r_blk, f_blk), lambda i, j, k, m: (j, k)),  # CAM high
+        pl.BlockSpec((r_blk, C_pad), lambda i, j, k, m: (j, 0)),  # leaf matrix
     ]
-    operands = [tile_mask, q, low, high, leaf]
+    operands = [q, low, high, leaf]
     if bias is not None:  # fused epilogue bias, one (1, C_pad) row
-        in_specs.append(pl.BlockSpec((1, C_pad), lambda i, j, k: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, C_pad), lambda i, j, k, m: (0, 0)))
         operands.append(bias)
 
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((b_blk, C_pad), lambda i, j, k: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # the flat tile-activity mask, in SMEM
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((b_blk, C_pad), lambda i, j, k, m: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((b_blk, r_blk), acc_dtype)],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, C_pad), jnp.float32),
-        scratch_shapes=scratch,
-        compiler_params=compiler_params,
+        # batch axis parallel; row + feature axes sequential (the scratch
+        # AND and the output tile accumulate in place)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
         interpret=interpret,
-    )(*operands)
+    )(tile_mask.reshape(-1), *operands)

@@ -12,6 +12,7 @@ leaf lookup; summing over all rows is the in-core ACC + NoC reduction.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import precision
@@ -37,7 +38,7 @@ def cam_match_ref(
     """
     if mode == "soft":
         match = precision.soft_match_scores(q, low, high, tau)  # (B, R)
-        return match @ leaf_matrix  # (B, C)
+        return _leaf_dot(match, leaf_matrix)  # (B, C)
     qe = q[:, None, :].astype(jnp.int32)  # (B, 1, F)
     lo = low[None, :, :].astype(jnp.int32)  # (1, R, F)
     hi = high[None, :, :].astype(jnp.int32)
@@ -56,7 +57,13 @@ def cam_match_ref(
             f"unknown mode {mode!r}; registered modes: {precision.mode_names()}"
         )
     match = jnp.all(cell, axis=-1)  # (B, R) — the MAL wired-AND over columns
-    return match.astype(leaf_matrix.dtype) @ leaf_matrix  # (B, C)
+    return _leaf_dot(match.astype(leaf_matrix.dtype), leaf_matrix)  # (B, C)
+
+
+def _leaf_dot(match: jnp.ndarray, leaf_matrix: jnp.ndarray) -> jnp.ndarray:
+    """``match @ leaf_matrix`` in full float32: a TPU's default matmul
+    precision would round the leaf values to bfloat16."""
+    return jnp.matmul(match, leaf_matrix, precision=jax.lax.Precision.HIGHEST)
 
 
 def cam_match_bits_ref(

@@ -297,9 +297,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
         t_compile = time.time() - t0 - t_lower
 
         mem = compiled.memory_analysis()
-        ca = compiled.cost_analysis() or {}
-        if isinstance(ca, list):  # jax 0.4.x returns [dict], >=0.5 a dict
-            ca = ca[0] if ca else {}
+        ca = compiled.cost_analysis()
         cost = hlo_analysis.analyze(compiled.as_text())
         n_dev = meta["n_devices"]
         terms = hlo_analysis.roofline_from_cost(

@@ -11,15 +11,10 @@ import jax
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    # jax >= 0.5 takes explicit axis_types (we want Auto everywhere);
-    # 0.4.x has no AxisType and its make_mesh is Auto-only already.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = (
-        {"axis_types": (axis_type.Auto,) * len(axes)}
-        if axis_type is not None
-        else {}
+    # Auto axes everywhere: the partitioner places what shard_map leaves
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
-    return jax.make_mesh(shape, axes, **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -25,7 +25,6 @@ import logging
 import threading
 from dataclasses import dataclass, field
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as kops
@@ -190,6 +189,35 @@ class MicroBatcher:
         with self._lock:
             return self._pending[0].t_enqueue if self._pending else None
 
+    def warm(self) -> None:
+        """Compile (and run once) everything a flush of each cached
+        bucket runs on the device, so no such flush waits on a compile."""
+        width = self.engine.table.n_features
+        for size in self.bucket.sizes():
+            self._run(np.zeros((1, width), dtype=np.int32), size)
+
+    def _run(self, q: np.ndarray, size: int) -> np.ndarray:
+        """Engine outputs for the rows ``q`` padded to ``size``.
+
+        Rows are padded on the host, in the table dtype, before anything
+        reaches the device: every device op below (narrowing, feature
+        padding, the engine program) is then keyed by the bucket alone.
+        Padding on the device keyed its eager ops by the request count,
+        and each new count compiled during a flush.
+        """
+        dtype = np.dtype(self.engine.table_dtype)
+        kops.check_query_range(q, dtype)
+        rows = np.zeros((size, q.shape[1]), dtype=dtype)
+        rows[: q.shape[0]] = q
+        # compressed tables dropped wildcard columns: narrow the full-width
+        # request rows to the stored columns BEFORE padding to f_pad —
+        # padding first would bake misaligned columns into the bucket
+        q_sel = self.engine.select_features(rows)
+        q_padded = kops.pad_to_bucket(
+            q_sel, size, self.engine.arrays.f_pad, dtype=dtype
+        )
+        return np.asarray(self.engine.padded_fn(self.kind)(q_padded))
+
     # -- flush ---------------------------------------------------------------
 
     def flush(self) -> dict[int, np.ndarray]:
@@ -205,16 +233,7 @@ class MicroBatcher:
             batch, self._pending = self._pending, []
         n = sum(p.n_rows for p in batch)
         size = self.bucket.select(n)
-        q = np.concatenate([p.q_bins for p in batch], axis=0)
-        # compressed tables dropped wildcard columns: narrow the full-width
-        # request rows to the stored columns BEFORE padding to f_pad —
-        # padding first would bake misaligned columns into the bucket
-        q_sel = self.engine.select_features(jnp.asarray(q))
-        q_padded = kops.pad_to_bucket(
-            q_sel, size, self.engine.arrays.f_pad,
-            dtype=self.engine.table_dtype,
-        )
-        out = np.asarray(self.engine.padded_fn(self.kind)(q_padded))
+        out = self._run(np.concatenate([p.q_bins for p in batch], axis=0), size)
         results: dict[int, np.ndarray] = {}
         row = 0
         for p in batch:

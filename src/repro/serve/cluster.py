@@ -465,6 +465,8 @@ class ClusterServer:
         ``TableRegistry.register`` path (compiling if needed); the
         resulting artifact is installed as-is on the other replicas —
         same table bits, so any replica serves bit-equal predictions.
+        Every replica's engine compiles all serving buckets before the
+        model is installed there (``_warm``).
         """
         with self._lock:
             if self._closed:
@@ -475,11 +477,11 @@ class ClusterServer:
             if not order:
                 raise RuntimeError("no live replicas to register on")
             primary, rest = order[0], order[1:]
-        entry = primary.registry.register(name, model, **kw)
+        entry = primary.registry.register(name, model, warmup=self._warm, **kw)
         for r in rest:
             r.registry.register(
                 name, entry.artifact, batching=entry.batching,
-                deploy=entry.deploy,
+                deploy=entry.deploy, warmup=self._warm,
             )
         with self._lock:
             self._catalog[name] = (entry.artifact, entry.deploy, entry.batching)
@@ -493,6 +495,14 @@ class ClusterServer:
                 ),
             )
         return entry
+
+    def _warm(self, engine) -> None:
+        # workers beat between jobs, so a flush that compiled a cold
+        # bucket would go as silent as a hang: compile every bucket here,
+        # on the registering thread, before any replica serves the engine
+        MicroBatcher.for_engine(
+            engine, max_batch=self.max_batch, kind=self.kind
+        ).warm()
 
     def models(self) -> list[str]:
         with self._lock:
@@ -538,7 +548,8 @@ class ClusterServer:
         replica = self._new_replica(replica_id)
         for name, (artifact, deploy, batching) in catalog.items():
             replica.registry.register(
-                name, artifact, deploy=deploy, batching=batching
+                name, artifact, deploy=deploy, batching=batching,
+                warmup=self._warm,
             )
         replica.start()
         with self._lock:

@@ -26,10 +26,13 @@ Thread safety: every registry operation (register/swap/unregister and
 all lookups) runs under one re-entrant lock, so the async cluster tier
 (``repro.serve.cluster``) can hot-swap from a control thread while
 worker threads resolve entries — a reader sees either the old or the
-new ``ServedModel``, never a torn one.  ``register`` holds the lock
-across its read-modify-write (version bump + settings carry-over), which
-serializes concurrent swaps of the same name; compiles are slow but
-swaps are rare, so serialization beats a torn version chain.
+new ``ServedModel``, never a torn one.  Writers additionally hold a
+second lock across their whole read-modify-write (version bump +
+settings carry-over), which serializes concurrent swaps of the same
+name; compiles are slow but swaps are rare, so serialization beats a
+torn version chain.  A ``register(..., warmup=fn)`` runs ``fn`` on the
+new engine before installing it and outside the lookup lock, so readers
+keep resolving the old entry while the new one compiles.
 """
 
 from __future__ import annotations
@@ -37,6 +40,7 @@ from __future__ import annotations
 import threading
 import warnings
 from dataclasses import dataclass, field
+from typing import Callable
 
 from jax.sharding import Mesh
 
@@ -125,7 +129,8 @@ class TableRegistry:
         self.chip_spec = chip_spec
         self.deploy = deploy  # None => per-model defaults / artifact config
         self._models: dict[str, ServedModel] = {}
-        self._lock = threading.RLock()
+        self._lock = threading.RLock()  # lookups and installs
+        self._write_lock = threading.RLock()  # whole register/swap/unregister
 
     # -- registration --------------------------------------------------------
 
@@ -136,6 +141,7 @@ class TableRegistry:
         *,
         batching: bool | None = None,
         deploy: DeployConfig | None = None,
+        warmup: Callable[[XTimeEngine], None] | None = None,
         **engine_overrides,
     ) -> ServedModel:
         """Install ``model`` under ``name`` (compiling only if needed).
@@ -149,6 +155,10 @@ class TableRegistry:
         its version incremented, with the previous registration's
         ``batching``/deploy settings carried over unless overridden.
 
+        ``warmup`` runs on the newly bound engine before the entry is
+        installed (the serving tier compiles its buckets there); lookups
+        meanwhile still see the previous registration.
+
         ``engine_overrides`` (loose ``backend=...`` kwargs) are deprecated
         in favor of ``deploy=DeployConfig(...)`` but still honored.
         """
@@ -159,13 +169,19 @@ class TableRegistry:
                 DeprecationWarning,
                 stacklevel=2,
             )
-        with self._lock:
-            return self._register_locked(
-                name, model, batching=batching, deploy=deploy,
-                **engine_overrides,
-            )
+        with self._write_lock:
+            with self._lock:
+                entry = self._bind_locked(
+                    name, model, batching=batching, deploy=deploy,
+                    **engine_overrides,
+                )
+            if warmup is not None:
+                warmup(entry.engine)
+            with self._lock:
+                self._models[name] = entry
+            return entry
 
-    def _register_locked(
+    def _bind_locked(
         self,
         name: str,
         model: Ensemble | CAMTable | CompiledModel,
@@ -208,20 +224,19 @@ class TableRegistry:
             batching=batching,
             engine_overrides=dict(engine_overrides),
         )
-        self._models[name] = entry
         return entry
 
     def swap(
         self, name: str, model: Ensemble | CAMTable | CompiledModel, **kw
     ) -> ServedModel:
         """Hot-swap: like ``register`` but the name must already exist."""
-        with self._lock:
-            if name not in self._models:
+        with self._write_lock:
+            if name not in self:
                 raise KeyError(f"cannot swap unknown model {name!r}")
-            return self._register_locked(name, model, **kw)
+            return self.register(name, model, **kw)
 
     def unregister(self, name: str) -> None:
-        with self._lock:
+        with self._write_lock, self._lock:
             try:
                 del self._models[name]
             except KeyError:

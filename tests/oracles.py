@@ -17,13 +17,17 @@ configurations) — is gated by the same two references:
 pins ``interpret=True``.  CI runs the harness under both settings.
 
 This module lives on the tests path (imported bare, like
-``_hypothesis_compat``); it holds shared fixtures/assertions only — no
-test functions.
+``_hypothesis_compat``); it holds shared fixtures, assertions and the
+8-fake-device subprocess runner only — no test functions.
 """
 
 from __future__ import annotations
 
+import json
 import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -49,6 +53,26 @@ def env_interpret_kernel() -> bool | None:
     """Interpret setting for direct ``cam_match`` calls: True or None
     (None defers to the kernel's per-platform resolution)."""
     return True if os.environ.get("XTIME_TEST_INTERPRET", "auto") == "1" else None
+
+
+def run_on_8_devices(code: str) -> dict:
+    """Run ``code`` in a subprocess on 8 fake CPU devices and return the
+    JSON object it prints last (the test process itself has one device).
+
+    The platform is pinned to the CPU: fake host devices need it anyway,
+    and an unset platform makes jax probe the TPU plugin, which stalls
+    on the (absent) cloud metadata server in sandboxed environments.
+    """
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        env=env, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 # -- table generators ----------------------------------------------------------

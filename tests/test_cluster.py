@@ -4,9 +4,11 @@ under live traffic, overload shedding, elastic restore, adaptive flush
 windows, deterministic traffic replay, and thread-safety of the shared
 MicroBatcher/TableRegistry (DESIGN.md §12)."""
 
+import dataclasses
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -337,6 +339,56 @@ def test_hot_swap_under_live_traffic(served):
         # post-swap requests always see the new version
         for i, h in enumerate(post):
             np.testing.assert_array_equal(h.result(5), pred_b[i : i + 1])
+
+
+def test_register_compiles_every_serving_bucket(served):
+    """Cold buckets compile in register, never in a flush: workers beat
+    between jobs, so a flush that compiled would go as silent as a hang
+    (or, at a few ms, read as a straggler)."""
+    art, _, xb = served
+    art = dataclasses.replace(art)  # fresh engine cache: nothing compiled
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    with _server() as srv:
+        entry = srv.register("m", art)
+        program = entry.engine._jitted("predict", donate=True)
+        sizes = MicroBatcher.for_engine(entry.engine, max_batch=128).bucket.sizes()
+        assert program._cache_size() == len(sizes)
+        handles = []
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            # every bucket, each reached by several request counts
+            for n in [1, 3, *(s + 1 for s in sizes[:-1]), 100, 127]:
+                handles.append((n, srv.submit("m", xb[:n])))
+                srv.drain(timeout=60)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_event)
+        assert compiles == []
+        assert srv.report()["failovers"] == 0
+        direct = np.asarray(art.engine().predict(xb))
+        for n, h in handles:
+            np.testing.assert_array_equal(h.result(5), direct[:n])
+
+
+def test_registry_warmup_runs_before_install(served):
+    """A hot swap's warmup sees the new engine while lookups still
+    resolve the old entry; the new one is installed only afterwards."""
+    art_a, art_b, _ = served
+    reg = TableRegistry()
+    reg.register("m", art_a)
+    seen = []
+
+    def warmup(engine):
+        live = reg.get("m")
+        seen.append((live.artifact is art_a, live.version, engine is live.engine))
+
+    entry = reg.register("m", art_b, warmup=warmup)
+    assert seen == [(True, 1, False)]
+    assert reg.version("m") == 2 and reg.engine("m") is entry.engine
 
 
 # -- admission control --------------------------------------------------------

@@ -34,10 +34,6 @@ from repro.launch.hlo_analysis import analyze
 from repro.launch.mesh import make_host_mesh
 mesh = make_host_mesh(2, 4)
 
-def _cost(compiled):
-    ca = compiled.cost_analysis()  # dict on jax >= 0.5, [dict] on 0.4.x
-    return ca[0] if isinstance(ca, list) else (ca or {})
-
 def body(x, w):
     return jnp.tanh(x @ w), None
 
@@ -62,7 +58,7 @@ a_unroll = analyze(cu.as_text())
 print(json.dumps({
     "scan_flops": a_scan.dot_flops,
     "unroll_flops": a_unroll.dot_flops,
-    "xla_unroll_flops": float(_cost(cu).get("flops", -1)),
+    "xla_unroll_flops": float(cu.cost_analysis().get("flops", -1)),
     "trips": a_scan.trip_counts,
     "expected": float(L * 16 * d * (d // 4) * 2),
 }))

@@ -18,12 +18,12 @@ from pathlib import Path
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as st
+from oracles import run_on_8_devices
 
 import repro
-from repro.api import CompiledModel, build
+from repro.api import build
 from repro.core.quantize import FeatureQuantizer
 from repro.core.trees import random_deep_ensemble
-from repro.launch.mesh import make_host_mesh
 from repro.score import (
     NpySource,
     PredictionWriter,
@@ -112,17 +112,29 @@ def test_empty_and_one_row_tails(binary_cm, multiclass_cm):
     assert r.n_chunks == 2
 
 
-def test_mesh_batch_noc_bit_equal(binary_cm):
+def test_mesh_batch_noc_bit_equal(binary_cm, tmp_path):
     """Chunks fan out across the 8-fake-device mesh under the 'batch'
     NoC program (replicated tables, no collective) — same bits."""
     cm, q, ref_m, _ = binary_cm
-    mesh = make_host_mesh(8, 1)
-    r = score_file(cm, q, kind="margin", chunk_rows=40, mesh=mesh)
-    np.testing.assert_array_equal(r.values, ref_m)
-    assert r.engine["devices"] == 8
-    assert r.engine["noc_config"] == "batch"
+    cm.save(tmp_path / "art")
+    np.save(tmp_path / "q.npy", q)
+    res = run_on_8_devices(f"""
+import json
+from repro.api import CompiledModel
+from repro.launch.mesh import make_host_mesh
+from repro.score import score_file
+
+r = score_file(CompiledModel.load({str(tmp_path / "art")!r}),
+               {str(tmp_path / "q.npy")!r}, kind="margin", chunk_rows=40,
+               mesh=make_host_mesh(8, 1))
+print(json.dumps({{"values": r.values.tolist(), "engine": r.engine,
+                  "bucket": r.bucket}}))
+""")
+    np.testing.assert_array_equal(np.asarray(res["values"], np.float32), ref_m)
+    assert res["engine"]["devices"] == 8
+    assert res["engine"]["noc_config"] == "batch"
     # the bucket must satisfy the mesh's batch-divisibility contract
-    assert r.bucket % 8 == 0
+    assert res["bucket"] % 8 == 0
 
 
 def test_float_input_binned_chunkwise_bit_equal():
@@ -245,19 +257,28 @@ def test_xgb_deep_golden_save_score_verify(tmp_path):
     exp = json.loads(
         (FIXTURES / "ingest" / "xgb_deep.expected.json").read_text()
     )
-    cm = build(str(FIXTURES / "ingest" / "xgb_deep.json"))
-    cm.save(tmp_path / "art")
-    loaded = CompiledModel.load(tmp_path / "art")
+    res = run_on_8_devices(f"""
+import json
+from repro.api import CompiledModel, build
+from repro.launch.mesh import make_host_mesh
+from repro.score import score_file
 
-    mesh = make_host_mesh(8, 1)
-    r = score_file(loaded, FIXTURES / "score" / "xgb_deep_x.npy",
-                   kind="margin", chunk_rows=10, mesh=mesh)
+build({str(FIXTURES / "ingest" / "xgb_deep.json")!r}).save({str(tmp_path / "art")!r})
+loaded = CompiledModel.load({str(tmp_path / "art")!r})
+mesh = make_host_mesh(8, 1)
+rows = {str(FIXTURES / "score" / "xgb_deep_x.npy")!r}
+r = score_file(loaded, rows, kind="margin", chunk_rows=10, mesh=mesh)
+rp = score_file(loaded, rows, kind="predict", chunk_rows=10, mesh=mesh)
+print(json.dumps({{"margin": r.values.tolist(), "predict": rp.values.tolist(),
+                  "devices": r.engine["devices"]}}))
+""")
+    assert res["devices"] == 8
     want = np.asarray(exp["raw_margin"], dtype=np.float32)
-    np.testing.assert_allclose(r.values, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(res["margin"], np.float32), want,
+                               rtol=1e-5, atol=1e-6)
     # regression fixture: predictions ARE margins (engine tolerance)
-    rp = score_file(loaded, FIXTURES / "score" / "xgb_deep_x.npy",
-                    kind="predict", chunk_rows=10, mesh=mesh)
-    np.testing.assert_allclose(rp.values, np.asarray(exp["predict"]),
+    np.testing.assert_allclose(np.asarray(res["predict"], np.float32),
+                               np.asarray(exp["predict"]),
                                rtol=1e-5, atol=1e-6)
 
 
