@@ -25,6 +25,10 @@ Three structural wins over naive whole-file one-shot scoring:
     ``CompiledModel.engine(batch_hint=...)`` so a tuned artifact's
     dispatch table picks the measured-best kernel for that bucket.
 
+Each stage of a chunk (read, bin, pad, dispatch, wait, fetch, write) and
+the engine's binding run under an ``xtime.score.<stage>`` profiler span
+(``repro.obs``), so a trace charges the device's idle time to a stage.
+
 Bit-equivalence contract: every CAM row match and leaf accumulation is
 per-query-row independent, so the concatenated streamed outputs are
 BIT-IDENTICAL to a single ``predict``/``raw_margin`` call over the whole
@@ -41,6 +45,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.obs import span
 from repro.score.reader import open_columnar
 from repro.score.writer import PredictionWriter
 
@@ -94,6 +99,16 @@ def _empty_tail(model, kind: str) -> tuple[tuple, np.dtype]:
     if model.table.task == "regression":
         return (), np.dtype(np.float32)
     return (), np.dtype(np.int32)
+
+
+def _drain(writer: PredictionWriter, start: int, n: int, dev) -> None:
+    """Wait for one chunk's outputs, fetch its ``n`` real rows, write them."""
+    with span("xtime.score.wait"):
+        dev.block_until_ready()
+    with span("xtime.score.fetch"):
+        out = np.asarray(dev)[:n]
+    with span("xtime.score.write"):
+        writer.write(start, out)
 
 
 def score_file(
@@ -171,11 +186,12 @@ def score_file(
         from repro.kernels import ops as kops  # lazy: touches jax
         from repro.core.tune import kernel_version
 
-        engine = model.engine(mesh=mesh, batch_hint=chunk_rows, **(
-            {"noc_config": "batch", **overrides}
-            if mesh is not None and "noc_config" not in overrides
-            else overrides
-        ))
+        with span("xtime.score.bind"):
+            engine = model.engine(mesh=mesh, batch_hint=chunk_rows, **(
+                {"noc_config": "batch", **overrides}
+                if mesh is not None and "noc_config" not in overrides
+                else overrides
+            ))
         # one bucket for every chunk (tail included): a single jit entry,
         # sized to what both the kernel tiling and the mesh accept
         mult = int(np.lcm(engine.b_blk, engine.batch_multiple))
@@ -186,28 +202,37 @@ def score_file(
         t0 = time.perf_counter()
         pending: tuple[int, int, object] | None = None
         n_chunks = 0
-        for start, chunk in src.iter_chunks(chunk_rows):
-            bins = quantizer.transform(chunk) if needs_grid else chunk
-            q = kops.pad_to_bucket(
-                engine.select_features(np.asarray(bins)),
-                bucket, engine.arrays.f_pad, dtype=engine.table_dtype,
-            )
+        chunks = src.iter_chunks(chunk_rows)
+        while True:
+            with span("xtime.score.read"):  # the slice and its copy
+                item = next(chunks, None)
+            if item is None:
+                break
+            start, chunk = item
+            if needs_grid:
+                with span("xtime.score.bin"):
+                    bins = quantizer.transform(chunk)
+            else:
+                bins = chunk
+            with span("xtime.score.pad"):
+                q = kops.pad_to_bucket(
+                    engine.select_features(np.asarray(bins)),
+                    bucket, engine.arrays.f_pad, dtype=engine.table_dtype,
+                )
             # dispatch is async: the device starts on this chunk (its
             # query buffer donated) while the host drains the previous
             # one and reads/bins the next — at most two chunks in flight
-            dev = run(q)
+            with span("xtime.score.dispatch"):
+                dev = run(q)
             n_chunks += 1
             if pending is not None:
-                p_start, p_len, p_dev = pending
-                writer.write(p_start, np.asarray(p_dev)[:p_len])
-                pending = None
+                _drain(writer, *pending)
             if double_buffer:
                 pending = (start, chunk.shape[0], dev)
             else:
-                writer.write(start, np.asarray(dev)[: chunk.shape[0]])
+                _drain(writer, start, chunk.shape[0], dev)
         if pending is not None:
-            p_start, p_len, p_dev = pending
-            writer.write(p_start, np.asarray(p_dev)[:p_len])
+            _drain(writer, *pending)
         values = writer.finalize()
         elapsed = time.perf_counter() - t0
 

@@ -13,6 +13,9 @@ request size and pay a full dispatch per request.  Instead the batcher:
   4. un-pads and splits the outputs back to the individual requests in
      their original order.
 
+Each step runs under an ``xtime.serve.<step>`` profiler span (coalesce,
+pad, dispatch, wait, fetch; ``repro.obs``).
+
 Batches larger than ``max_batch`` still get served: the fallback bucket is
 the next ``b_blk`` multiple (an uncached compile — logged, not fatal),
 mirroring how the chip handles over-capacity models by spilling to
@@ -28,6 +31,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.kernels import ops as kops
+from repro.obs import span
 
 log = logging.getLogger(__name__)
 
@@ -196,8 +200,9 @@ class MicroBatcher:
         for size in self.bucket.sizes():
             self._run(np.zeros((1, width), dtype=np.int32), size)
 
-    def _run(self, q: np.ndarray, size: int) -> np.ndarray:
-        """Engine outputs for the rows ``q`` padded to ``size``.
+    def _run(self, q: np.ndarray, size: int):
+        """Engine outputs, on the device, for the rows ``q`` padded to
+        ``size``.
 
         Rows are padded on the host, in the table dtype, before anything
         reaches the device: every device op below (narrowing, feature
@@ -205,20 +210,40 @@ class MicroBatcher:
         Padding on the device keyed its eager ops by the request count,
         and each new count compiled during a flush.
         """
-        dtype = np.dtype(self.engine.table_dtype)
-        kops.check_query_range(q, dtype)
-        rows = np.zeros((size, q.shape[1]), dtype=dtype)
-        rows[: q.shape[0]] = q
-        # compressed tables dropped wildcard columns: narrow the full-width
-        # request rows to the stored columns BEFORE padding to f_pad —
-        # padding first would bake misaligned columns into the bucket
-        q_sel = self.engine.select_features(rows)
-        q_padded = kops.pad_to_bucket(
-            q_sel, size, self.engine.arrays.f_pad, dtype=dtype
-        )
-        return np.asarray(self.engine.padded_fn(self.kind)(q_padded))
+        with span("xtime.serve.pad"):
+            dtype = np.dtype(self.engine.table_dtype)
+            kops.check_query_range(q, dtype)
+            rows = np.zeros((size, q.shape[1]), dtype=dtype)
+            rows[: q.shape[0]] = q
+            # compressed tables dropped wildcard columns: narrow the
+            # full-width request rows to the stored columns BEFORE padding
+            # to f_pad — padding first would bake misaligned columns into
+            # the bucket
+            q_sel = self.engine.select_features(rows)
+            q_padded = kops.pad_to_bucket(
+                q_sel, size, self.engine.arrays.f_pad, dtype=dtype
+            )
+        with span("xtime.serve.dispatch"):
+            out = self.engine.padded_fn(self.kind)(q_padded)
+        with span("xtime.serve.wait"):
+            return out.block_until_ready()
 
     # -- flush ---------------------------------------------------------------
+
+    def serve(self, blocks: list[np.ndarray]) -> list[np.ndarray]:
+        """Outputs for each block of query rows, in order, from one
+        coalesced engine call over all of them."""
+        with span("xtime.serve.coalesce"):
+            q = np.concatenate(blocks, axis=0)
+            size = self.bucket.select(q.shape[0])
+        out = self._run(q, size)
+        with span("xtime.serve.fetch"):
+            out = np.asarray(out)
+            parts, row = [], 0
+            for b in blocks:
+                parts.append(out[row : row + b.shape[0]])
+                row += b.shape[0]
+        return parts
 
     def flush(self) -> dict[int, np.ndarray]:
         """Run one coalesced engine call; returns {request_id: outputs}.
@@ -231,12 +256,5 @@ class MicroBatcher:
             if not self._pending:
                 return {}
             batch, self._pending = self._pending, []
-        n = sum(p.n_rows for p in batch)
-        size = self.bucket.select(n)
-        out = self._run(np.concatenate([p.q_bins for p in batch], axis=0), size)
-        results: dict[int, np.ndarray] = {}
-        row = 0
-        for p in batch:
-            results[p.request_id] = out[row : row + p.n_rows]
-            row += p.n_rows
-        return results
+        outs = self.serve([p.q_bins for p in batch])
+        return {p.request_id: out for p, out in zip(batch, outs)}

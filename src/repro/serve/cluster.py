@@ -37,6 +37,14 @@ artifact, a request is completed exactly once (first writer wins), and a
 re-routed request re-executes the same deterministic computation on a
 survivor (tests/test_cluster.py).
 
+Observability: each flush runs under an ``xtime.serve.flush`` profiler
+span (``repro.obs``) whose children are the batcher's stages, and each
+dispatcher pass that routes work under ``xtime.serve.route``.  Every
+request carries the time it entered its stage, so ``report()`` gives
+cumulative queue, inbox and service seconds that add up to the
+requests' latencies; completed requests are kept in numpy ring arrays,
+not one Python object each.
+
 Fault-injection hooks (``inject_crash`` / ``inject_hang`` /
 ``inject_delay`` / ``restore_replica``) make every degradation mode
 testable on the 8-fake-device CPU harness — no real hardware needs to
@@ -59,8 +67,9 @@ from typing import Callable
 import numpy as np
 
 from repro.ft.runtime import Heartbeat, StragglerMonitor
+from repro.obs import GC2, span
 from repro.serve.batching import MicroBatcher
-from repro.serve.loop import LatencyStats, RequestRecord
+from repro.serve.loop import LatencyStats
 from repro.serve.registry import ServedModel, TableRegistry
 
 log = logging.getLogger(__name__)
@@ -178,6 +187,10 @@ class _Pending:
     handle: ClusterHandle
     q_bins: np.ndarray
     t_enqueue: float
+    # when the request entered the stage it is in (queue, inbox, flush):
+    # each stage's wait runs from here, so the stages of a completed
+    # request add up to its t_done - t_enqueue, requeues included
+    t_stage: float
 
 
 @dataclass
@@ -195,6 +208,46 @@ class _Job:
 
 class _InjectedCrash(RuntimeError):
     pass
+
+
+class _RequestRing:
+    """The last ``capacity`` completed requests, oldest first, in four
+    preallocated arrays (model index, rows, enqueue and done times): no
+    Python object per request for the cyclic collector to scan."""
+
+    def __init__(self, capacity: int) -> None:
+        self.model = np.zeros(capacity, np.int32)
+        self.rows = np.zeros(capacity, np.int64)
+        self.t_enqueue = np.zeros(capacity)
+        self.t_done = np.zeros(capacity)
+        self.n = 0  # entries held
+        self._next = 0  # where the next entry goes
+        self._ids: dict[str, int] = {}
+
+    def add(self, model: str, rows: list[int], t_enqueue: list[float],
+            t_done: float) -> None:
+        cap = len(self.rows)
+        k = min(len(rows), cap)
+        if k == 0:
+            return
+        at = (self._next + np.arange(k)) % cap
+        self.model[at] = self._ids.setdefault(model, len(self._ids))
+        self.rows[at] = rows[-k:]
+        self.t_enqueue[at] = t_enqueue[-k:]
+        self.t_done[at] = t_done
+        self._next = (self._next + k) % cap
+        self.n = min(cap, self.n + k)
+
+    def clear(self) -> None:
+        self.n = 0
+
+    def select(self, model: str | None = None) -> np.ndarray:
+        """Positions of the held entries (of ``model``), oldest first."""
+        cap = len(self.rows)
+        at = (self._next - self.n + np.arange(self.n)) % max(cap, 1)
+        if model is not None:
+            at = at[self.model[at] == self._ids.get(model, -1)]
+        return at
 
 
 class Replica:
@@ -231,6 +284,12 @@ class Replica:
         self.served_requests = 0
         self.served_rows = 0
         self.n_flushes = 0
+        # cumulative from start, never reset: requests whose flush began,
+        # their seconds from routing to that flush's start, and completed
+        # requests' seconds from flush start to completion
+        self.started_requests = 0
+        self.inbox_wait_s = 0.0
+        self.service_s = 0.0
         self.delay_s = 0.0
         self._beat_interval_s = beat_interval_s
         self._server = server
@@ -301,18 +360,32 @@ class Replica:
 
     def _process(self, job: _Job) -> None:
         t0 = time.perf_counter()
-        if self.delay_s > 0.0:
-            time.sleep(self.delay_s)  # injected straggler: counts as flush time
-        batcher, _ = self._batcher(job.model)
+        server = self._server
+        start = server.clock()
+        wait = 0.0
         for p in job.requests:
-            batcher.submit(
-                p.q_bins, t_enqueue=p.t_enqueue,
-                request_id=p.handle.request_id,
-            )
-        results = batcher.flush()  # blocks until device results are ready
-        dt = time.perf_counter() - t0
-        self.n_flushes += 1
-        self._server._job_done(self, job, results, dt)
+            wait += start - p.t_stage
+            p.t_stage = start
+        self.started_requests += len(job.requests)
+        self.inbox_wait_s += wait
+        with span(
+            "xtime.serve.flush", model=job.model, replica=self.id,
+            seq=self.n_flushes, rows=job.n_rows,
+            # the cumulative counters, so a trace can difference them
+            routed_requests=server._routed.get(job.model, 0),
+            queue_wait_s=server._queue_wait_s.get(job.model, 0.0),
+            started_requests=self.started_requests,
+            inbox_wait_s=self.inbox_wait_s,
+        ):
+            if self.delay_s > 0.0:
+                time.sleep(self.delay_s)  # injected straggler: flush time
+            batcher, _ = self._batcher(job.model)
+            # blocks until device results are ready
+            outs = batcher.serve([p.q_bins for p in job.requests])
+            dt = time.perf_counter() - t0
+            self.n_flushes += 1
+            with span("xtime.serve.complete"):
+                server._job_done(self, job, outs, dt)
 
 
 class ClusterServer:
@@ -394,8 +467,12 @@ class ClusterServer:
         self._queue_rows: dict[str, int] = {}
         self._windows: dict[str, AdaptiveWindow] = {}
         self._shed: dict[str, int] = {}
-        self._records: deque[RequestRecord] = deque(maxlen=history)
+        self._records = _RequestRing(history)
         self._n_flushes: dict[str, int] = {}
+        # per model, cumulative from start and never reset: routings
+        # (requeues included) and their seconds in the model queue
+        self._routed: dict[str, int] = {}
+        self._queue_wait_s: dict[str, float] = {}
         self._outstanding = 0
         self._failovers = 0
         self._next_rid = itertools.count()
@@ -406,6 +483,8 @@ class ClusterServer:
 
         # liveness observer (reads every worker file in run_dir)
         self._observer = Heartbeat(self.run_dir, -1, timeout_s=heartbeat_timeout_s)
+        GC2.hold()
+        self._gc2_at_start = (GC2.passes, GC2.seconds)
         self.replicas: dict[int, Replica] = {}
         for rid in range(n_replicas):
             self.replicas[rid] = self._new_replica(rid)
@@ -453,6 +532,7 @@ class ClusterServer:
         self._dispatcher.join(timeout=5.0)
         for r in self.replicas.values():
             r._thread.join(timeout=1.0)  # hung replicas are daemon threads
+        GC2.release()
         if self._owns_run_dir:
             shutil.rmtree(self.run_dir, ignore_errors=True)
 
@@ -589,7 +669,7 @@ class ClusterServer:
                 )
             handle = ClusterHandle(model, next(self._next_rid), q.shape[0])
             self._queues.setdefault(model, deque()).append(
-                _Pending(handle, q, now)
+                _Pending(handle, q, now, now)
             )
             self._queue_rows[model] = rows + q.shape[0]
             self._windows[model].observe(now, q.shape[0])
@@ -620,11 +700,23 @@ class ClusterServer:
     def _live_replicas(self) -> list[Replica]:
         return [r for r in self.replicas.values() if r.state == ALIVE]
 
-    def _route_locked(self, job: _Job) -> bool:
+    def _route_locked(self, job: _Job, now: float) -> bool:
         """Round-robin the job to the next live replica; False if none."""
         live = self._live_replicas()
         if not live:
             return False
+        # stamped before the job reaches an inbox, where its replica may
+        # start it at once
+        wait = 0.0
+        for p in job.requests:
+            wait += now - p.t_stage
+            p.t_stage = now
+        self._routed[job.model] = (
+            self._routed.get(job.model, 0) + len(job.requests)
+        )
+        self._queue_wait_s[job.model] = (
+            self._queue_wait_s.get(job.model, 0.0) + wait
+        )
         for _ in range(len(self.replicas)):
             rid = next(self._rr)
             replica = self.replicas.get(rid)
@@ -634,10 +726,12 @@ class ClusterServer:
         live[0].inbox.put(job)  # rotation missed (membership changed)
         return True
 
-    def _pop_jobs_locked(self, now: float) -> list[_Job]:
+    def _due_locked(self, now: float) -> list[str]:
+        """Models whose queue flushes now: a full bucket, an expired
+        window or a forced drain."""
         if not self._live_replicas():
             return []  # park everything until a restore/monitor pass
-        jobs: list[_Job] = []
+        due: list[str] = []
         for model, qd in self._queues.items():
             if any(p.handle.done() for p in qd):
                 # requeued copies that lost the completion race to their
@@ -651,22 +745,27 @@ class ClusterServer:
             window = self._windows[model].window_s
             rows = self._queue_rows.get(model, 0)
             expired = now - qd[0].t_enqueue >= window
-            if not (rows >= self.flush_rows or expired or self._force_flush):
-                continue
-            while qd:
-                batch: list[_Pending] = [qd.popleft()]
-                n = batch[0].handle.n_rows
-                while qd and n + qd[0].handle.n_rows <= self.max_batch:
-                    p = qd.popleft()
-                    batch.append(p)
-                    n += p.handle.n_rows
-                jobs.append(_Job(model, batch))
-                # below the flush target and not forced: leave the rest
-                # to coalesce further (only the expired/full head goes)
-                remaining = sum(p.handle.n_rows for p in qd)
-                if remaining < self.flush_rows and not self._force_flush:
-                    break
-            self._queue_rows[model] = sum(p.handle.n_rows for p in qd)
+            if rows >= self.flush_rows or expired or self._force_flush:
+                due.append(model)
+        return due
+
+    def _pop_jobs_locked(self, model: str) -> list[_Job]:
+        qd = self._queues[model]
+        jobs: list[_Job] = []
+        while qd:
+            batch: list[_Pending] = [qd.popleft()]
+            n = batch[0].handle.n_rows
+            while qd and n + qd[0].handle.n_rows <= self.max_batch:
+                p = qd.popleft()
+                batch.append(p)
+                n += p.handle.n_rows
+            jobs.append(_Job(model, batch))
+            # below the flush target and not forced: leave the rest
+            # to coalesce further (only the expired/full head goes)
+            remaining = sum(p.handle.n_rows for p in qd)
+            if remaining < self.flush_rows and not self._force_flush:
+                break
+        self._queue_rows[model] = sum(p.handle.n_rows for p in qd)
         return jobs
 
     def _next_deadline_locked(self, now: float) -> float:
@@ -691,14 +790,11 @@ class ClusterServer:
                         self._cond.wait(timeout=timeout)
                     if self._closed:
                         return
-                    for job in self._pop_jobs_locked(self.clock()):
-                        if not self._route_locked(job):
-                            # no live replica: park the job at the front
-                            qd = self._queues.setdefault(job.model, deque())
-                            qd.extendleft(reversed(job.requests))
-                            self._queue_rows[job.model] = sum(
-                                p.handle.n_rows for p in qd
-                            )
+                    now = self.clock()
+                    due = self._due_locked(now)
+                    if due:
+                        with span("xtime.serve.route", models=len(due)):
+                            self._route_due_locked(due, now)
                 now = time.monotonic()
                 if now - last_monitor >= self._monitor_interval_s:
                     last_monitor = now
@@ -706,6 +802,17 @@ class ClusterServer:
             except Exception:  # noqa: BLE001 - dispatcher must survive
                 log.exception("dispatcher iteration failed; continuing")
                 time.sleep(self._monitor_interval_s)
+
+    def _route_due_locked(self, due: list[str], now: float) -> None:
+        for model in due:
+            for job in self._pop_jobs_locked(model):
+                if not self._route_locked(job, now):
+                    # no live replica: park the job at the front
+                    qd = self._queues.setdefault(job.model, deque())
+                    qd.extendleft(reversed(job.requests))
+                    self._queue_rows[job.model] = sum(
+                        p.handle.n_rows for p in qd
+                    )
 
     # -- failure handling ----------------------------------------------------
 
@@ -794,28 +901,25 @@ class ClusterServer:
         self,
         replica: Replica,
         job: _Job,
-        results: dict[int, np.ndarray],
+        outs: list[np.ndarray],
         flush_dt: float,
     ) -> None:
+        """Complete a flushed job's requests (``outs`` in request order)."""
         t_done = self.clock()
-        completed = 0
-        records = []
-        for p in job.requests:
-            out = results.get(p.handle.request_id)
-            if out is None:  # pragma: no cover - batcher contract violation
-                continue
+        service = 0.0
+        rows: list[int] = []
+        t_enqueue: list[float] = []
+        for p, out in zip(job.requests, outs):
             if p.handle._complete(out):
-                completed += 1
-                records.append(
-                    RequestRecord(
-                        job.model, p.handle.request_id, p.handle.n_rows,
-                        p.t_enqueue, t_done,
-                    )
-                )
+                service += t_done - p.t_stage
+                rows.append(p.handle.n_rows)
+                t_enqueue.append(p.t_enqueue)
+        completed = len(rows)
         with self._lock:
             replica.served_requests += completed
-            replica.served_rows += sum(r.n_rows for r in records)
-            self._records.extend(records)
+            replica.service_s += service
+            replica.served_rows += sum(rows)
+            self._records.add(job.model, rows, t_enqueue, t_done)
             self._n_flushes[job.model] = self._n_flushes.get(job.model, 0) + 1
             self._outstanding -= completed
             # shared straggler accounting, normalized PER ROW: flush wall
@@ -858,20 +962,35 @@ class ClusterServer:
     def stats(self, model: str | None = None) -> LatencyStats:
         """p50/p99 accounting, same type the synchronous loop reports."""
         with self._lock:
-            records = [
-                r for r in self._records if model is None or r.model == model
-            ]
+            ring = self._records
+            at = ring.select(model)
+            t_enqueue, t_done, rows = (
+                ring.t_enqueue[at], ring.t_done[at], ring.rows[at]
+            )
             n_flushes = (
                 sum(self._n_flushes.values())
                 if model is None
                 else self._n_flushes.get(model, 0)
             )
-        return LatencyStats.from_records(records, n_flushes)
+        return LatencyStats.from_arrays(t_enqueue, t_done, rows, n_flushes)
 
     def report(self, model: str | None = None) -> dict:
-        """Cluster health + SLO accounting in one dict."""
+        """Cluster health + SLO accounting in one dict.
+
+        ``measured`` covers the requests since the last ``reset_stats``.
+        The stage counters are cumulative from the server's start and
+        never reset, so a reader differences them over its own window:
+        ``routed_requests`` and ``queue_wait_s`` (``model``'s, or every
+        model's), each replica's ``started_requests``, ``inbox_wait_s``
+        and ``service_s``, and the interpreter's full collections
+        (``gc2_passes``, ``gc2_s``).
+        """
         s = self.stats(model)
         with self._lock:
+            def of_model(counts: dict):
+                return (sum(counts.values()) if model is None
+                        else counts.get(model, 0))
+
             return {
                 "model": model,
                 "measured": {
@@ -894,12 +1013,19 @@ class ClusterServer:
                 "queue_rows": {
                     m: n for m, n in self._queue_rows.items() if n
                 },
+                "routed_requests": of_model(self._routed),
+                "queue_wait_s": of_model(self._queue_wait_s),
+                "gc2_passes": GC2.passes - self._gc2_at_start[0],
+                "gc2_s": GC2.seconds - self._gc2_at_start[1],
                 "replicas": {
                     r.id: {
                         "state": r.state,
                         "served_requests": r.served_requests,
                         "served_rows": r.served_rows,
                         "flushes": r.n_flushes,
+                        "started_requests": r.started_requests,
+                        "inbox_wait_s": r.inbox_wait_s,
+                        "service_s": r.service_s,
                     }
                     for r in self.replicas.values()
                 },

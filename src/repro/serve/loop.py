@@ -69,19 +69,35 @@ class LatencyStats:
         cls, records: "list[RequestRecord] | deque", n_flushes: int
     ) -> "LatencyStats":
         records = list(records)
-        if not records:
+        return cls.from_arrays(
+            np.array([r.t_enqueue for r in records], dtype=np.float64),
+            np.array([r.t_done for r in records], dtype=np.float64),
+            np.array([r.n_rows for r in records], dtype=np.int64),
+            n_flushes,
+        )
+
+    @classmethod
+    def from_arrays(
+        cls,
+        t_enqueue: np.ndarray,
+        t_done: np.ndarray,
+        n_rows: np.ndarray,
+        n_flushes: int,
+    ) -> "LatencyStats":
+        """The same statistics from per-request arrays, oldest first."""
+        if len(t_done) == 0:
             return cls(0, 0, 0.0, 0.0, 0.0, 0.0, 0.0, n_flushes)
-        lat_ms = np.array([r.latency_s for r in records]) * 1e3
-        span = max(r.t_done for r in records) - min(r.t_enqueue for r in records)
-        span = max(span, 1e-9)
+        lat_ms = (t_done - t_enqueue) * 1e3
+        span = max(float(t_done.max()) - float(t_enqueue.min()), 1e-9)
+        rows = int(n_rows.sum())
         return cls(
-            n_requests=len(records),
-            n_rows=sum(r.n_rows for r in records),
+            n_requests=len(t_done),
+            n_rows=rows,
             p50_ms=float(np.percentile(lat_ms, 50)),
             p99_ms=float(np.percentile(lat_ms, 99)),
             mean_ms=float(lat_ms.mean()),
-            requests_per_s=len(records) / span,
-            samples_per_s=sum(r.n_rows for r in records) / span,
+            requests_per_s=len(t_done) / span,
+            samples_per_s=rows / span,
             n_flushes=n_flushes,
         )
 
