@@ -135,14 +135,15 @@ def run() -> list[dict]:
         low, high, leaf, r_blk=256, n_bins=256, dtype="uint8"
     )
     mask = kops.wildcard_tile_mask(
-        lo_p, hi_p, r_blk=256, f_blk=128, n_bins=256, inclusive=True
+        lo_p, hi_p, r_blk=256, f_blk=32, n_bins=256, inclusive=True,
+        n_feat=f,
     )
-    qp = kops.pad_queries(jnp.asarray(q8), lo_p.shape[1], b_blk=128, dtype="uint8")
+    qp = kops.pad_queries(jnp.asarray(q8), lo_p.shape[0], b_blk=128, dtype="uint8")
     args = (qp, jnp.asarray(lo_p), jnp.asarray(hi_p), jnp.asarray(lm),
             jnp.asarray(mask))
     us = time_call(
         lambda: kops.cam_match(
-            *args, out_b=b, out_c=c, b_blk=128, r_blk=256, f_blk=128,
+            *args, out_b=b, out_c=c, b_blk=128, r_blk=256, f_blk=32, n_feat=f,
             mode="inclusive",
         ).block_until_ready()
     )

@@ -57,10 +57,7 @@ def _bench_config(cfg: dict) -> list[dict]:
         n_trees=cfg["n_trees"], depth=cfg["depth"],
         n_features=cfg["n_features"], n_bins=N_BINS, seed=20260808,
     )
-    # f_blk pinned to the true width: the jnp path must not pad
-    # F -> 128 (8x dead compute would swamp what's being measured)
-    cm = build(ens, deploy=DeployConfig(backend="jnp",
-                                        f_blk=cfg["n_features"]))
+    cm = build(ens, deploy=DeployConfig(backend="jnp"))
     rng = np.random.default_rng(0)
     q = rng.integers(0, N_BINS, size=(cfg["batch"], cfg["n_features"]))
     q = q.astype(np.int32)

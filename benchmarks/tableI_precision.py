@@ -33,7 +33,7 @@ def run() -> list[dict]:
     high = np.minimum(low + rng.integers(0, 256, size=(r, f)), 256).astype(np.int32)
     leaf = rng.normal(size=(r, cch)).astype(np.float32)
     lo_p, hi_p, leaf_p = kops.pad_tables(low, high, leaf, n_bins=256)
-    q_p = kops.pad_queries(jnp.asarray(rng.integers(0, 256, (b, f))), lo_p.shape[1])
+    q_p = kops.pad_queries(jnp.asarray(rng.integers(0, 256, (b, f))), lo_p.shape[0])
     for mode in ("direct", "msb_lsb", "two_cycle"):
         us = time_call(
             lambda: kops.cam_match(

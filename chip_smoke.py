@@ -275,8 +275,8 @@ def phase_four_chips(size: dict, on_tpu: bool) -> None:
         _compare_margins(f"4/{noc}", np.asarray(res.values), ref)
         eng = cm.engine(mesh=mesh, batch_hint=size["chunk"], noc_config=noc)
         _check(eng.spmd == "shard_map", f"4/{noc}: spmd {eng.spmd}")
-        shards = eng.arrays.low.addressable_shards
-        rows = sorted({s.data.shape[0] for s in shards})
+        shards = eng.arrays.low.addressable_shards  # (F_pad, rows) each
+        rows = sorted({s.data.shape[1] for s in shards})
         _check(len({s.device for s in shards}) == 4,
                f"4/{noc}: table on {len(shards)} shards")
         want = eng.arrays.r_pad // 4 if noc == "accumulate" else eng.arrays.r_pad

@@ -109,7 +109,7 @@ class CAMTable:
         return 1.0 - dc.mean(axis=0)
 
     def row_tile_activity(self, f_blk: int) -> np.ndarray:
-        """(R, ceil(F/f_blk)) bool — which feature tiles each row actually
+        """(R, ceil(F/f_blk)) bool — which feature groups each row actually
         constrains (non-wildcard).  The shared primitive behind
         ``tile_activity`` and the wildcard row ordering;
         ``kops.wildcard_tile_mask`` is the padded/packed kernel-side twin.
@@ -131,9 +131,10 @@ class CAMTable:
         return np.packbits(self.row_tile_activity(f_blk), axis=1)
 
     def tile_activity(self, r_blk: int, f_blk: int) -> np.ndarray:
-        """(ceil(R/r_blk), ceil(F/f_blk)) bool — does any cell of the tile
-        hold a real (non-wildcard) range?  An all-wildcard tile matches
-        every query, so the v2 kernel skips its compare entirely."""
+        """(ceil(R/r_blk), ceil(F/f_blk)) bool — does any cell of the
+        (row tile, feature group) hold a real (non-wildcard) range?  An
+        all-wildcard group matches every query, so the kernel skips its
+        compares entirely."""
         rows = self.row_tile_activity(f_blk)
         R, nf = rows.shape
         nr = max(1, -(-R // r_blk))
@@ -142,8 +143,8 @@ class CAMTable:
         return padded.reshape(nr, r_blk, nf).any(axis=1)
 
     def tile_skip_fraction(self, r_blk: int, f_blk: int) -> float:
-        """Fraction of (r_blk, f_blk) compare tiles the v2 kernel skips —
-        what wildcard-aware row ordering maximizes."""
+        """Fraction of (row tile, feature group) compares the kernel skips
+        — what wildcard-aware row ordering maximizes."""
         act = self.tile_activity(r_blk, f_blk)
         return float(1.0 - act.mean()) if act.size else 0.0
 
@@ -194,15 +195,19 @@ def validate_ensemble(ens: Ensemble) -> None:
 
 
 def order_rows_by_wildcards(table: CAMTable, f_blk: int = 128) -> CAMTable:
-    """Cluster rows by which feature tiles they actually constrain.
+    """Cluster rows by which feature groups they actually constrain.
 
     Tree rows are overwhelmingly wildcards (MonoSparse-CAM,
     arXiv:2407.11071): a depth-d path constrains ≤ d of F features.
     Sorting rows by their per-feature-tile activity bitmask groups rows
-    that are all-wildcard in the same ``f_blk``-wide tile into the same
-    row blocks, turning those (r_blk, f_blk) tiles into skippable
-    no-ops for the v2 kernel.  Stable sort: rows with identical
-    activity keep their tree-traversal order.
+    that are all-wildcard in the same ``f_blk``-wide feature group into
+    the same row tiles, turning those (row tile, group) compares into
+    skippable no-ops for the kernel.  ``compile_ensemble`` orders by
+    128-feature blocks (the default), which keeps a table's row order,
+    and so its margins' float order, independent of the kernel's group
+    size; a row wildcard over a whole block is wildcard in each of its
+    groups.  Stable sort: rows with identical activity keep their
+    tree-traversal order.
     """
     # bit-packed per-row activity masks: byte-lexicographic order equals
     # the numeric order of the full bitmask (tile 0 = MSB), at any tile

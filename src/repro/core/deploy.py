@@ -59,8 +59,9 @@ class DeployConfig:
         batch sharding (plus a leading 'pod' axis when present).
       b_blk / r_blk: kernel batch/row tile sizes — also the padding
         granularity of queries and CAM rows.
-      f_blk: feature tile width of the v2 kernel's third grid dimension
-        (lane multiple; features pad to it, DESIGN.md §10).
+      f_blk: feature group size of the kernel's compare loop — the
+        unit of wildcard skipping (one tile-mask entry per row tile and
+        group, DESIGN.md §10); features pad to the sublane tile alone.
       table_dtype: kernel table dtype.  'auto' takes the compile-time
         selection carried on the ``CAMTable`` (uint8 for ≤256 bins,
         uint16 to 65536, int32 beyond); an explicit packed dtype
@@ -77,7 +78,7 @@ class DeployConfig:
         (default) resolves at engine-bind time: compiled on TPU,
         interpreted elsewhere — callers no longer hard-code it.
       fuse_epilogue: fuse the epilogue's base-score add into the Pallas
-        kernel's last feature tile (kernel v3) — bit-identical, saves
+        kernel's last row tile (kernel v3) — bit-identical, saves
         the separate epilogue pass's HBM round-trip.  'auto' (default)
         fuses exactly when eligible: backend='pallas' with no mesh (a
         row-sharded psum would count the base once per shard).  True
@@ -101,7 +102,7 @@ class DeployConfig:
     batch_axis: str = "data"
     b_blk: int = 128
     r_blk: int = 256
-    f_blk: int = 128
+    f_blk: int = 16
     table_dtype: str = "auto"
     tau: float = 0.1
     c_mult: int = 8

@@ -8,8 +8,10 @@ constructor form is deprecated.
 Kernel v2 (DESIGN.md §10): at bind time the engine packs the canonical
 int32 exclusive-high table into the narrowest dtype the grid permits
 (``resolve_table_dtype`` — uint8 for ≤256 bins, inclusive upper bounds,
-compared natively), precomputes the wildcard tile-activity mask the
-kernel uses to skip all-wildcard compare tiles, and resolves
+compared natively), transposes the bounds feature-major ``(F_pad,
+R_pad)`` (CAM rows on the kernel's lanes; the jnp reference transposes
+them back), precomputes the wildcard group-activity mask the kernel
+uses to skip all-wildcard feature groups, and resolves
 ``interpret='auto'`` against the bound platform.  All of it is
 semantics-free: every (backend, mode, table_dtype) combination computes
 identical bits (tests/test_kernel_v2.py).
@@ -80,13 +82,14 @@ def resolve_table_dtype(table: CAMTable, config: DeployConfig) -> str:
 
 @dataclass
 class EngineArrays:
-    low: jnp.ndarray  # (R_pad, F_pad) table dtype
+    low: jnp.ndarray  # (F_pad, R_pad) table dtype, feature-major
     high: jnp.ndarray  # (inclusive upper bounds when packed)
     leaf: jnp.ndarray  # (R_pad, C_pad) float32
-    tile_mask: jnp.ndarray  # (R_pad/r_blk, F_pad/f_blk) int32
+    tile_mask: jnp.ndarray  # (R_pad/r_blk, n_groups) int32
     r_pad: int
     f_pad: int
     c_pad: int
+    n_feat: int  # the table's real width; the kernel compares no more
     table_dtype: str = "int32"
     inclusive: bool = False  # high bounds stored inclusive?
 
@@ -207,7 +210,7 @@ class XTimeEngine:
         # regardless of the config's tau knob
         self.tau = float(config.tau) if self.kernel_mode == "soft" else 0.0
         # kernel v3 fused epilogue: the base-score add rides the kernel's
-        # last feature tile.  Only the single-device pallas path is
+        # last row tile.  Only the single-device pallas path is
         # eligible — under a row-sharded mesh the per-shard partials are
         # psum'd, which would count the base once per shard.
         eligible = self.backend == "pallas" and mesh is None
@@ -250,24 +253,30 @@ class XTimeEngine:
         row_mult = self.r_blk
         if mesh is not None and self.noc_config in ("accumulate", "hybrid"):
             row_mult = self.r_blk * mesh.shape[self.row_axis]
+        # feature-major bounds (F_pad, R_pad), transposed here once
         low, high, leaf, inclusive = kops.pack_tables(
             table.low, table.high, table.leaf_matrix(),
             r_blk=row_mult, c_mult=config.c_mult, n_bins=table.n_bins,
-            f_blk=self.f_blk, dtype=self.table_dtype,
+            dtype=self.table_dtype,
             inclusive=(True if self.kernel_mode == "inclusive" else None),
         )
+        n_feat = max(1, table.low.shape[1])
         tile_mask = kops.wildcard_tile_mask(
             low, high, r_blk=self.r_blk, f_blk=self.f_blk,
-            n_bins=table.n_bins, inclusive=inclusive,
+            n_bins=table.n_bins, inclusive=inclusive, n_feat=n_feat,
         )
+        # the share of (row tile, feature group) compares the kernel runs:
+        # 1.0 means wildcard skipping never engages on this table
+        self.mask_active_share = float(tile_mask.mean())
         self.arrays = EngineArrays(
             low=jnp.asarray(low),
             high=jnp.asarray(high),
             leaf=jnp.asarray(leaf),
             tile_mask=jnp.asarray(tile_mask),
-            r_pad=low.shape[0],
-            f_pad=low.shape[1],
+            r_pad=low.shape[1],
+            f_pad=low.shape[0],
             c_pad=leaf.shape[1],
+            n_feat=n_feat,
             table_dtype=self.table_dtype,
             inclusive=inclusive,
         )
@@ -328,20 +337,35 @@ class XTimeEngine:
         return P(tuple(axes))
 
     def _row_spec(self) -> P:
+        """Row sharding of the leaf matrix and the tile mask (rows first)."""
         if self.noc_config == "batch":
             return P()  # table replicated in every core group
         return P(self.row_axis)
 
+    def _bound_spec(self) -> P:
+        """Row sharding of the feature-major bounds: rows are axis 1."""
+        if self.noc_config == "batch":
+            return P()
+        return P(None, self.row_axis)
+
+    def _table_specs(self) -> tuple[P, P, P, P]:
+        """Specs of (low, high, leaf, tile_mask)."""
+        rs, bs = self._row_spec(), self._bound_spec()
+        return bs, bs, rs, rs
+
     def _place_on_mesh(self) -> None:
         assert self.mesh is not None
-        rs = NamedSharding(self.mesh, self._row_spec())
-        self.arrays.low = jax.device_put(self.arrays.low, rs)
-        self.arrays.high = jax.device_put(self.arrays.high, rs)
-        self.arrays.leaf = jax.device_put(self.arrays.leaf, rs)
+        a = self.arrays
+        lo_s, hi_s, leaf_s, mask_s = (
+            NamedSharding(self.mesh, s) for s in self._table_specs()
+        )
+        a.low = jax.device_put(a.low, lo_s)
+        a.high = jax.device_put(a.high, hi_s)
+        a.leaf = jax.device_put(a.leaf, leaf_s)
         # the tile-activity mask shards with the rows it describes
-        self.arrays.tile_mask = jax.device_put(self.arrays.tile_mask, rs)
+        a.tile_mask = jax.device_put(a.tile_mask, mask_s)
         if self._moments is not None:  # soft moments shard like the leaves
-            self._moments = jax.device_put(self._moments, rs)
+            self._moments = jax.device_put(self._moments, leaf_s)
 
     # -- compute -----------------------------------------------------------
 
@@ -353,7 +377,7 @@ class XTimeEngine:
         passes None (no base score belongs in the raw moment sums)."""
         backend, mode, tau = self.backend, self.kernel_mode, self.tau
         b_blk, r_blk, f_blk = self.b_blk, self.r_blk, self.f_blk
-        interpret = self.interpret
+        interpret, n_feat = self.interpret, self.arrays.n_feat
         if bias is _UNSET:
             bias = self._bias
 
@@ -363,9 +387,10 @@ class XTimeEngine:
                     q, low, high, leaf, mask, bias,
                     out_b=q.shape[0], out_c=leaf.shape[1],
                     b_blk=b_blk, r_blk=r_blk, f_blk=f_blk,
-                    mode=mode, interpret=interpret, tau=tau,
+                    mode=mode, interpret=interpret, tau=tau, n_feat=n_feat,
                 )
-            return cam_match_ref(q, low, high, leaf, mode=mode, tau=tau)
+            # the reference compares row-major (R, F) tables
+            return cam_match_ref(q, low.T, high.T, leaf, mode=mode, tau=tau)
 
         return kernel
 
@@ -427,11 +452,11 @@ class XTimeEngine:
                     )
                 return out
 
-            qs, rs = self._batch_spec(), self._row_spec()
+            qs = self._batch_spec()
             # replication checking off: the Pallas kernel body is opaque
             # to the varying-manual-axes checker
             return jax.shard_map(
-                body, mesh=self.mesh, in_specs=(qs, rs, rs, rs, rs),
+                body, mesh=self.mesh, in_specs=(qs, *self._table_specs()),
                 out_specs=qs, check_vma=False,
             )
         return kernel
@@ -472,10 +497,9 @@ class XTimeEngine:
         donate_kw = {"donate_argnums": (0,)} if donate else {}
         if self.mesh is not None:
             bs = NamedSharding(self.mesh, self._batch_spec())
-            rs = NamedSharding(self.mesh, self._row_spec())
-            out_s = NamedSharding(self.mesh, self._batch_spec())
-            jfn = jax.jit(fn, in_shardings=(bs, rs, rs, rs, rs),
-                          out_shardings=out_s, **donate_kw)
+            ts = tuple(NamedSharding(self.mesh, s) for s in self._table_specs())
+            jfn = jax.jit(fn, in_shardings=(bs, *ts), out_shardings=bs,
+                          **donate_kw)
         else:
             jfn = jax.jit(fn, **donate_kw)
         self._fn_cache[cache_key] = jfn
@@ -666,8 +690,8 @@ class XTimeEngine:
         assert self.mesh is not None, "dry-run requires a mesh"
         margin = self._margin_fn()
         bs = NamedSharding(self.mesh, self._batch_spec())
-        rs = NamedSharding(self.mesh, self._row_spec())
-        return margin, (bs, rs, rs, rs, rs), bs
+        ts = tuple(NamedSharding(self.mesh, s) for s in self._table_specs())
+        return margin, (bs, *ts), bs
 
     def compiled_text(self, kind: str = "margin") -> str:
         """HLO text of the compiled ``kind`` program at its smallest

@@ -3,15 +3,16 @@
 Handles the padding contract so callers can pass ragged real-world shapes:
   * batch  -> multiple of b_blk          (pad queries with zeros)
   * rows   -> multiple of r_blk          (pad with never-match ranges)
-  * feats  -> multiple of f_blk lanes    (pad with always-match ranges)
+  * feats  -> the dtype's sublane tile   (pad with always-match ranges)
   * chans  -> multiple of 8              (pad leaf channels with zeros)
 and strips the padding from the output.
 
-Kernel v2 additions (DESIGN.md §10): ``pack_tables`` converts the padded
-exclusive-high int32 layout into the compact inclusive-high form in a
-narrow unsigned dtype, and ``wildcard_tile_mask`` precomputes the
-per-(row-tile, feature-tile) activity map the kernel uses to skip
-all-wildcard compare tiles.
+The padded bound tables are FEATURE-MAJOR, ``(F_pad, R_pad)``: CAM rows on
+the lanes, as the kernel compares them (DESIGN.md §10); the leaf matrix
+stays ``(R_pad, C_pad)``.  ``pack_tables`` converts the exclusive-high
+int32 layout into the compact inclusive-high form in a narrow unsigned
+dtype, and ``wildcard_tile_mask`` precomputes the per-(row-tile,
+feature-group) activity map the kernel uses to skip all-wildcard groups.
 """
 
 from __future__ import annotations
@@ -22,8 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.cam_match import F_CHUNK, cam_match_pallas
-from repro.kernels.ref import cam_match_ref
+from repro.kernels.cam_match import (
+    F_CHUNK, cam_match_pallas, n_groups, sublane_rows,
+)
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -38,27 +40,17 @@ def pad_tables(
     r_blk: int = 256,
     c_mult: int = 8,
     n_bins: int | None = None,
-    f_blk: int = F_CHUNK,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Pad the compiled CAM table to kernel-friendly shapes (host-side).
 
-    Output stays in the canonical exclusive-high int32 layout; use
-    :func:`pack_tables` for the compact-dtype kernel form.
+    Output stays in the canonical exclusive-high int32 encoding, bounds
+    feature-major ``(F_pad, R_pad)``; use :func:`pack_tables` for the
+    compact-dtype kernel form.
     """
-    R, F = low.shape
-    C = leaf_matrix.shape[1]
-    R_pad, F_pad, C_pad = _ceil_to(R, r_blk), _ceil_to(F, f_blk), _ceil_to(C, c_mult)
-    big = np.int32(n_bins if n_bins is not None else (int(high.max()) + 1))
-
-    lo = np.zeros((R_pad, F_pad), dtype=np.int32)
-    hi = np.full((R_pad, F_pad), big, dtype=np.int32)  # always-match columns
-    lo[:R, :F] = low
-    hi[:R, :F] = high
-    lo[R:, :] = 1  # never-match rows: low=1 > high=0
-    hi[R:, :] = 0
-
-    lm = np.zeros((R_pad, C_pad), dtype=np.float32)
-    lm[:R, :C] = leaf_matrix
+    lo, hi, lm, _ = pack_tables(
+        low, high, leaf_matrix, r_blk=r_blk, c_mult=c_mult, n_bins=n_bins,
+        dtype="int32", inclusive=False,
+    )
     return lo, hi, lm
 
 
@@ -70,11 +62,15 @@ def pack_tables(
     r_blk: int = 256,
     c_mult: int = 8,
     n_bins: int | None = None,
-    f_blk: int = F_CHUNK,
     dtype: str = "int32",
     inclusive: bool | None = None,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, bool]:
     """Pad + pack the CAM table for the kernel; returns (lo, hi, leaf, incl).
+
+    ``lo``/``hi`` are feature-major ``(F_pad, R_pad)``, features padded to
+    the dtype's sublane tile (:func:`sublane_rows`); ``leaf`` is
+    ``(R_pad, C_pad)``.  The transpose from the table's ``(R, F)`` happens
+    here, once, at bind.
 
     ``dtype`` is the kernel table dtype.  The packed (unsigned) dtypes
     always store INCLUSIVE upper bounds so the full grid [0, n_bins)
@@ -102,8 +98,7 @@ def pack_tables(
     dt = np.dtype(dtype)
     if dt.kind == "f":
         return _pack_tables_soft(
-            low, high, leaf_matrix,
-            r_blk=r_blk, c_mult=c_mult, n_bins=n_bins, f_blk=f_blk,
+            low, high, leaf_matrix, r_blk=r_blk, c_mult=c_mult, n_bins=n_bins,
         )
     if inclusive is None:
         inclusive = dt.kind == "u"
@@ -125,22 +120,23 @@ def pack_tables(
 
     R, F = low.shape
     C = leaf_matrix.shape[1]
-    R_pad, F_pad, C_pad = _ceil_to(R, r_blk), _ceil_to(F, f_blk), _ceil_to(C, c_mult)
+    out_dt = dt if dt.kind == "u" else np.dtype(np.int32)
+    R_pad, C_pad = _ceil_to(R, r_blk), _ceil_to(C, c_mult)
+    F_pad = _ceil_to(max(F, 1), sublane_rows(out_dt))
     big = n_bins if n_bins is not None else (int(high.max(initial=0)) + 1)
 
-    lo = np.zeros((R_pad, F_pad), dtype=np.int64)
-    hi = np.full(  # always-match columns in the chosen encoding
-        (R_pad, F_pad), big - 1 if inclusive else big, dtype=np.int64
+    lo = np.zeros((F_pad, R_pad), dtype=out_dt)
+    hi = np.full(  # always-match features in the chosen encoding
+        (F_pad, R_pad), big - 1 if inclusive else big, dtype=out_dt
     )
-    lo[:R, :F] = lo_enc
-    hi[:R, :F] = hi_enc
-    lo[R:, :] = 1  # never-match rows: low=1 > high=0 in both encodings
-    hi[R:, :] = 0
+    lo[:F, :R] = lo_enc.T
+    hi[:F, :R] = hi_enc.T
+    lo[:, R:] = 1  # never-match rows: low=1 > high=0 in both encodings
+    hi[:, R:] = 0
 
     lm = np.zeros((R_pad, C_pad), dtype=np.float32)
     lm[:R, :C] = leaf_matrix
-    out_dt = dt if dt.kind == "u" else np.int32
-    return lo.astype(out_dt), hi.astype(out_dt), lm, inclusive
+    return lo, hi, lm, inclusive
 
 
 def _pack_tables_soft(
@@ -151,18 +147,16 @@ def _pack_tables_soft(
     r_blk: int,
     c_mult: int,
     n_bins: int | None,
-    f_blk: int,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, bool]:
     """The float32 soft-mode layout: pad in the canonical int32 form,
-    then apply ``precision.encode_soft_bounds`` so padding columns become
+    then apply ``precision.encode_soft_bounds`` so padding features become
     exact wildcards (log-score 0) and padding rows exact never-matches
     (score 0) — no soft weight ever leaks out of the real table."""
     from repro.core.precision import encode_soft_bounds
 
     bins = int(n_bins) if n_bins is not None else (int(high.max(initial=0)) + 1)
     lo, hi, lm = pad_tables(
-        low, high, leaf_matrix,
-        r_blk=r_blk, c_mult=c_mult, n_bins=bins, f_blk=f_blk,
+        low, high, leaf_matrix, r_blk=r_blk, c_mult=c_mult, n_bins=bins,
     )
     lo_f, hi_f = encode_soft_bounds(lo, hi, bins)
     return lo_f, hi_f, lm, False
@@ -176,27 +170,35 @@ def wildcard_tile_mask(
     f_blk: int,
     n_bins: int,
     inclusive: bool,
+    n_feat: int | None = None,
 ) -> np.ndarray:
-    """(R/r_blk, F/f_blk) int32 — 0 marks an all-wildcard compare tile.
+    """(R/r_blk, n_groups) int32 — 0 marks an all-wildcard compare group.
 
-    Operates on PADDED (and possibly packed) tables: a wildcard cell is
-    the full range [0, n_bins) in whichever encoding ``inclusive``
-    names; on float32 soft-encoded tables it is the exact (-inf, +inf)
-    cell (log-score 0, so a skipped tile contributes nothing to the
-    kernel's running log-sum — skipping stays semantics-free).
-    Never-match padding rows are not wildcards, so their tiles stay
+    Operates on PADDED (and possibly packed) feature-major tables
+    ``(F_pad, R_pad)``; a group is ``f_blk`` of the first ``n_feat``
+    features (default all ``F_pad``), the kernel's unit of skipping.  A
+    wildcard cell is the full range [0, n_bins) in whichever encoding
+    ``inclusive`` names; on float32 soft-encoded tables it is the exact
+    (-inf, +inf) cell (log-score 0, so a skipped group contributes nothing
+    to the kernel's running log-sum — skipping stays semantics-free).
+    Never-match padding rows are not wildcards, so their groups stay
     active and keep their rows unmatchable.
     """
-    R, F = low.shape
-    if R % r_blk or F % f_blk:
-        raise ValueError(f"padded shape ({R}, {F}) must tile by ({r_blk}, {f_blk})")
+    F_pad, R = low.shape
+    n_feat = F_pad if n_feat is None else n_feat
+    if R % r_blk:
+        raise ValueError(f"padded rows {R} must tile by r_blk={r_blk}")
+    lo, hi = low[:n_feat], high[:n_feat]
     if np.dtype(low.dtype).kind == "f":
-        act = ~(np.isneginf(low) & np.isposinf(high))
+        act = ~(np.isneginf(lo) & np.isposinf(hi))
     else:
         top = n_bins - 1 if inclusive else n_bins
-        act = ~((low.astype(np.int64) == 0) & (high.astype(np.int64) >= top))
-    tiles = act.reshape(R // r_blk, r_blk, F // f_blk, f_blk).any(axis=(1, 3))
-    return tiles.astype(np.int32)
+        act = ~((lo.astype(np.int64) == 0) & (hi.astype(np.int64) >= top))
+    n_g = n_groups(n_feat, f_blk)
+    grouped = np.zeros((n_g * f_blk, R), dtype=bool)
+    grouped[:n_feat] = act
+    tiles = grouped.reshape(n_g, f_blk, R // r_blk, r_blk).any(axis=(1, 3))
+    return np.ascontiguousarray(tiles.T).astype(np.int32)
 
 
 def pad_queries(
@@ -258,7 +260,7 @@ def pad_to_bucket(
     jax.jit,
     static_argnames=(
         "b_blk", "r_blk", "f_blk", "mode", "interpret", "out_b", "out_c",
-        "tau",
+        "tau", "n_feat",
     ),
 )
 def cam_match(
@@ -277,9 +279,13 @@ def cam_match(
     mode: str = "direct",
     interpret: bool | None = None,
     tau: float = 0.0,
+    n_feat: int | None = None,
 ) -> jnp.ndarray:
     """Kernel entry on pre-padded operands; returns unpadded (out_b, out_c).
 
+    ``low``/``high`` are feature-major ``(F_pad, R_pad)``; ``n_feat`` is
+    the table's real width (default ``F_pad``), past which nothing is
+    compared.
     ``bias`` is the optional (1, C_pad) fused-epilogue row added inside
     the kernel on each output tile's last visit (kernel v3); callers
     fusing it must NOT add the base score again downstream.  ``tau`` is
@@ -289,15 +295,6 @@ def cam_match(
     out = cam_match_pallas(
         q_padded, low, high, leaf, tile_mask, bias,
         b_blk=b_blk, r_blk=r_blk, f_blk=f_blk, mode=mode, interpret=interpret,
-        tau=tau,
+        tau=tau, n_feat=n_feat,
     )
     return out[:out_b, :out_c]
-
-
-@jax.jit
-def cam_match_jnp(
-    q: jnp.ndarray, low: jnp.ndarray, high: jnp.ndarray, leaf_matrix: jnp.ndarray
-) -> jnp.ndarray:
-    """XLA-fused fallback (no Pallas) — used by the distributed engine where
-    the row axis is mesh-sharded and by CPU-only paths."""
-    return cam_match_ref(q, low, high, leaf_matrix, mode="direct")

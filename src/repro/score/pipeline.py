@@ -222,7 +222,8 @@ def score_file(
             # dispatch is async: the device starts on this chunk (its
             # query buffer donated) while the host drains the previous
             # one and reads/bins the next — at most two chunks in flight
-            with span("xtime.score.dispatch"):
+            with span("xtime.score.dispatch",
+                      mask_active=engine.mask_active_share):
                 dev = run(q)
             n_chunks += 1
             if pending is not None:

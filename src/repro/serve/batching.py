@@ -223,7 +223,8 @@ class MicroBatcher:
             q_padded = kops.pad_to_bucket(
                 q_sel, size, self.engine.arrays.f_pad, dtype=dtype
             )
-        with span("xtime.serve.dispatch"):
+        with span("xtime.serve.dispatch",
+                  mask_active=self.engine.mask_active_share):
             out = self.engine.padded_fn(self.kind)(q_padded)
         with span("xtime.serve.wait"):
             return out.block_until_ready()

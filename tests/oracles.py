@@ -139,19 +139,19 @@ def run_encoding(q, low, high, leaf, *, n_bins, dtype, mode, backend, b, c):
     )
     assert incl == (np.dtype(dtype).kind == "u")
     mask = kops.wildcard_tile_mask(
-        lo_p, hi_p, r_blk=32, f_blk=128, n_bins=n_bins, inclusive=incl,
+        lo_p, hi_p, r_blk=32, f_blk=8, n_bins=n_bins, inclusive=incl,
     )
     kernel_mode = "inclusive" if incl else mode
-    qp = kops.pad_queries(jnp.asarray(q), lo_p.shape[1], b_blk=32, dtype=dtype)
+    qp = kops.pad_queries(jnp.asarray(q), lo_p.shape[0], b_blk=32, dtype=dtype)
     if backend == "pallas":
         out = kops.cam_match(
             qp, jnp.asarray(lo_p), jnp.asarray(hi_p), jnp.asarray(lm),
-            jnp.asarray(mask), out_b=b, out_c=c, b_blk=32, r_blk=32,
+            jnp.asarray(mask), out_b=b, out_c=c, b_blk=32, r_blk=32, f_blk=8,
             mode=kernel_mode, interpret=env_interpret_kernel(),
         )
     else:
-        out = cam_match_ref(
-            qp, jnp.asarray(lo_p), jnp.asarray(hi_p), jnp.asarray(lm),
+        out = cam_match_ref(  # the reference reads row-major tables
+            qp, jnp.asarray(lo_p.T), jnp.asarray(hi_p.T), jnp.asarray(lm),
             mode=kernel_mode,
         )[:b, :c]
     return np.asarray(out)

@@ -12,9 +12,10 @@ def test_inclusive_uint8_kernel_matches_oracle():
     rng = np.random.default_rng(11)
     b, r, f, c = 128, 512, 128, 8
     q, low, high, leaf = compact_problem(rng, b, r, f, c)
-    out = cam_match_pallas(
-        jnp.asarray(q), jnp.asarray(low), jnp.asarray(high), jnp.asarray(leaf),
-        b_blk=128, r_blk=256, mode="inclusive", interpret=True,
+    out = cam_match_pallas(  # the kernel reads feature-major tables
+        jnp.asarray(q), jnp.asarray(low.T), jnp.asarray(high.T),
+        jnp.asarray(leaf), b_blk=128, r_blk=256, mode="inclusive",
+        interpret=True,
     )
     ref = cam_match_ref(
         jnp.asarray(q), jnp.asarray(low), jnp.asarray(high), jnp.asarray(leaf),

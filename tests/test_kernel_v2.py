@@ -181,8 +181,8 @@ def test_row_ordering_increases_skippable_tiles_and_preserves_bits():
 
 def test_engine_mask_actually_skips_and_stays_correct():
     """A pallas engine on a table whose constraints live entirely in the
-    first feature tile must skip the second tile's compares — and still
-    agree with the jnp oracle to the last bit."""
+    first feature group must skip every other group's compares — and
+    still agree with the jnp oracle to the last bit."""
     from repro.core.compile import CAMTable
 
     rng = np.random.default_rng(6)
@@ -204,8 +204,10 @@ def test_engine_mask_actually_skips_and_stays_correct():
         table, DeployConfig(backend="pallas", b_blk=32, r_blk=32),
     )
     mask = np.asarray(eng.arrays.tile_mask)
-    assert mask.shape == (2, 2)
-    np.testing.assert_array_equal(mask[:, 1], 0)  # tile 1: all wildcards
+    assert mask.shape == (2, 13)  # 200 features in groups of 16
+    np.testing.assert_array_equal(mask[:, 0], 1)
+    np.testing.assert_array_equal(mask[:, 1:], 0)  # all wildcards
+    assert eng.mask_active_share == 2 / 26
     xq = rng.integers(0, n_bins, size=(96, F))
     ref = np.asarray(
         XTimeEngine.from_config(

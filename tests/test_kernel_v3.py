@@ -158,14 +158,14 @@ def test_none_mask_is_exactly_full_tile_mask():
     """tile_mask=None must be the EXPLICIT every-tile-active fallback:
     bit-identical output to passing full_tile_mask, never a silent skip."""
     q, low, high, leaf = _mask_problem()
-    kw = dict(b_blk=32, r_blk=32, mode="inclusive",
+    kw = dict(b_blk=32, r_blk=32, f_blk=128, mode="inclusive",
               interpret=env_interpret_kernel())
     out_none = cam_match_pallas(
-        jnp.asarray(q), jnp.asarray(low), jnp.asarray(high),
+        jnp.asarray(q), jnp.asarray(low.T), jnp.asarray(high.T),
         jnp.asarray(leaf), None, **kw,
     )
     out_full = cam_match_pallas(
-        jnp.asarray(q), jnp.asarray(low), jnp.asarray(high),
+        jnp.asarray(q), jnp.asarray(low.T), jnp.asarray(high.T),
         jnp.asarray(leaf), full_tile_mask(2, 2), **kw,
     )
     np.testing.assert_array_equal(np.asarray(out_none), np.asarray(out_full))
@@ -182,9 +182,9 @@ def test_misshapen_tile_mask_rejected(bad_shape):
     q, low, high, leaf = _mask_problem()
     with pytest.raises(ValueError, match=r"\(2, 2\)"):
         cam_match_pallas(
-            jnp.asarray(q), jnp.asarray(low), jnp.asarray(high),
+            jnp.asarray(q), jnp.asarray(low.T), jnp.asarray(high.T),
             jnp.asarray(leaf), jnp.ones(bad_shape, jnp.int32),
-            b_blk=32, r_blk=32, mode="inclusive",
+            b_blk=32, r_blk=32, f_blk=128, mode="inclusive",
             interpret=env_interpret_kernel(),
         )
 
